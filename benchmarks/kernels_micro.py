@@ -1,18 +1,23 @@
 """Kernel microbenchmarks (interpret-mode shapes: correctness-scale only;
 wall times on CPU are NOT TPU perf - the derived column reports the
 kernel's modeled HBM traffic advantage vs the unfused jnp path instead).
+Interpret mode only, so it refuses to run where JAX sees a TPU.
 """
 from __future__ import annotations
 
 import time
 
-import jax.numpy as jnp
 import numpy as np
 
 from benchmarks.common import row
 
 
 def run():
+    import jax
+    import jax.numpy as jnp
+    if jax.default_backend() == "tpu":
+        raise SystemExit("kernels_micro times interpret-mode kernels on the "
+                         "host; it is not a TPU benchmark")
     rows = []
     rng = np.random.default_rng(0)
 

@@ -15,6 +15,9 @@ from benchmarks import (anchors, appf_large_message, fig8_single_straggler,
                         table1_bounds)
 from benchmarks.common import emit
 
+# `kernels` runs last: it is the only module that starts JAX, and
+# `sweep` forks a process pool, which must not come from a process that
+# holds a JAX backend (on a TPU host, the chip).
 MODULES = [
     ("fig8", fig8_single_straggler),
     ("fig9", fig9_multi_straggler),
@@ -22,9 +25,9 @@ MODULES = [
     ("table1", table1_bounds),
     ("schedgen", schedule_gen_speed),
     ("appF", appf_large_message),
-    ("kernels", kernels_micro),
     ("anchors", anchors),
     ("sweep", sweep_summary),
+    ("kernels", kernels_micro),
 ]
 
 

@@ -1,6 +1,6 @@
 """Failover equivalence on a real 8-member DP ring (virtual devices).
 
-Spawns a subprocess with 8 forced host devices and trains the same model
+Spawns a subprocess with 8 forced host (CPU) devices and trains the same model
 twice: once healthy (native psum gradient sync) and once with member 3
 degraded to 4/7 bandwidth (OptCC sync). The parameter trajectories must
 match to fp tolerance - the paper's algorithm changes WHERE bytes flow,
@@ -33,8 +33,11 @@ sys.path.insert(0, str(pathlib.Path(__file__).resolve().parent.parent
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
 
+# The child is a CPU demo: it pins JAX to the host's 8 virtual devices, so
+# on a TPU machine it neither claims nor waits for the chip.
 CHILD = r"""
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np, jax, jax.numpy as jnp
 from jax.sharding import Mesh
@@ -63,7 +66,8 @@ steps = {
     "degraded": make_dp_failover_step(model, mesh, opt, constant(1e-3),
                                       fault),
 }
-states = {k: init_train_state(model, opt, seed=11) for k in steps}
+states = {k: init_train_state(model, opt, seed=11, mesh=mesh)
+          for k in steps}
 for i in range(5):
     b = jax.tree.map(jnp.asarray, data.batch(i))
     line = f"step {i}:"

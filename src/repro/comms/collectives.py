@@ -18,7 +18,8 @@ On real hardware the fine-grained segment pipelining of Section 4.2 is the
 transport layer's concern (core.schedule / core.simulator model it); at the
 XLA level what matters is which links carry how many bytes, which is what
 this module controls. Functional equivalence with psum is tested on 8 host
-devices (tests/test_collectives_multidev.py).
+devices (tests/test_collectives_multidev.py) and on four TPU chips
+(`chip_smoke.py --chips 4`).
 
 Also here: hierarchical cross-pod psum and int8-compressed gradient sync
 with error feedback (distributed-optimization extras used by train.step).

@@ -8,8 +8,10 @@ fp32-accumulates the W incoming ways per block, so each output element is
 written once and each input element read once.
 
 Grid: one program per element block. BlockSpec keeps the W-way stack of
-one block resident in VMEM ((W, BLOCK) <= ~4 MB for W<=16, BLOCK=131072
-bf16) - within v5e's 128 MB VMEM budget with double buffering.
+one block resident in VMEM, double-buffered, next to its fp32 upcast.
+The block is capped so that this stays within VMEM_BUDGET, half of the
+16 MiB a v5e kernel may use by default: a larger one is refused by the
+compiler (e.g. W=16 f32 at 131072 elements).
 """
 from __future__ import annotations
 
@@ -20,7 +22,18 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 LANES = 128
+TILE = 8 * LANES               # one (8, 128) vreg tile of elements
 DEFAULT_BLOCK = 16 * 1024
+VMEM_BUDGET = 8 * 1024 * 1024
+
+
+def max_block(ways: int, in_dtype, out_dtype) -> int:
+    """Largest block (a multiple of TILE) whose buffers fit VMEM_BUDGET:
+    double-buffered (W, block) input and (block,) output, plus the
+    (W, block) fp32 upcast."""
+    per_elem = (2 * (ways * jnp.dtype(in_dtype).itemsize
+                     + jnp.dtype(out_dtype).itemsize) + 4 * ways)
+    return max(TILE, VMEM_BUDGET // per_elem // TILE * TILE)
 
 
 def _kernel(x_ref, o_ref):
@@ -35,7 +48,8 @@ def chunk_reduce_pallas(parts: jax.Array, block: int = DEFAULT_BLOCK,
                         interpret: bool = False, out_dtype=None):
     W, N = parts.shape
     out_dtype = out_dtype or parts.dtype
-    block = min(block, max(LANES, ((N + LANES - 1) // LANES) * LANES))
+    block = min(block, max_block(W, parts.dtype, out_dtype),
+                max(LANES, ((N + LANES - 1) // LANES) * LANES))
     pad = (-N) % block
     if pad:
         parts = jnp.pad(parts, ((0, 0), (0, pad)))

@@ -13,8 +13,9 @@ def chunk_reduce(parts: jax.Array, block: int = DEFAULT_BLOCK,
                  out_dtype=None) -> jax.Array:
     """Sum W partial buffers: (W, N) -> (N,), fp32 accumulation.
 
-    use_pallas=False falls back to the jnp oracle (the default on
-    non-TPU backends unless interpret=True is requested).
+    use_pallas=False runs the jnp oracle instead; interpret=True runs the
+    kernel in the Pallas interpreter (no TPU). `block` is capped to what
+    fits VMEM (kernel.max_block).
     """
     if not use_pallas:
         return chunk_reduce_ref(parts, out_dtype)

@@ -12,7 +12,8 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                     bq: int = 128, bkv: int = 128,
                     use_pallas: bool = True,
                     interpret: bool = False) -> jax.Array:
-    """Blockwise attention; falls back to the jnp oracle off-TPU."""
+    """Blockwise attention. use_pallas=False runs the jnp oracle instead;
+    interpret=True runs the kernel in the Pallas interpreter (no TPU)."""
     if not use_pallas:
         return flash_attention_ref(q, k, v, causal=causal, window=window)
     return flash_attention_pallas(q, k, v, causal=causal, window=window,

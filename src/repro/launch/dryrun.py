@@ -57,6 +57,10 @@ TRAIN_MICROBATCHES = {
 # hillclimb iteration 0 in EXPERIMENTS.md SPerf).
 TRAIN_REMAT_DEFAULT = "full"
 
+# The chip the production mesh stands for (a key of roofline.PEAKS): the
+# dry-run compiles on host devices, whose own kind says nothing.
+TARGET_DEVICE_KIND = "TPU v5 lite"
+
 
 def _sds_like(shapes_tree, shardings_tree):
     return jax.tree.map(
@@ -214,7 +218,8 @@ def run_cell(arch: str, shape: ShapeCell, mesh_kind: str, tag="baseline",
             bytes_hbm=hlo.hbm_bytes,
             bytes_collective=hlo.collective_bytes,
             model_flops=model_flops,
-            chips=chips)
+            chips=chips,
+            device_kind=TARGET_DEVICE_KIND)
 
         rec.update({
             "status": "ok",

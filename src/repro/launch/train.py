@@ -10,6 +10,7 @@ Runs data-parallel training with:
 
 Works on any device count >= 1 (the DP axis is however many devices jax
 sees; force more with XLA_FLAGS=--xla_force_host_platform_device_count=8).
+--fail-at needs >= 3 devices and exits non-zero on fewer.
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen3-1.7b --smoke \
       --steps 200 --fail-at 60 --repair-at 120 --ckpt-dir /tmp/ckpt
@@ -17,21 +18,32 @@ sees; force more with XLA_FLAGS=--xla_force_host_platform_device_count=8).
 from __future__ import annotations
 
 import argparse
+import sys
+import tempfile
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import latest_step, restore, save
 from repro.comms.fault import FailureInjector, FaultState
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLM
+from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
 from repro.optim import AdamWConfig
 from repro.optim.schedules import warmup_stable_decay
 from repro.train import init_train_state, make_dp_failover_step
+
+
+def rebuild_step(model, mesh, opt, lr_fn, fault: FaultState, n_grad: int):
+    """React to a changed fault state: plan the collective for `n_grad`
+    gradient elements (None when healthy) and build the new step - the
+    NCCL communicator re-init analogue. The step compiles on first call."""
+    plan = fault.plan(n_grad) if fault.degraded else None
+    return make_dp_failover_step(model, mesh, opt, lr_fn, fault), plan
 
 
 def main(argv=None):
@@ -58,6 +70,7 @@ def main(argv=None):
                          "survivors, restore, continue (elastic rescale)")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     cfg = get_config(args.arch, smoke=args.smoke)
     model = build_model(cfg)
     dp = jax.device_count()
@@ -72,21 +85,21 @@ def main(argv=None):
     injector = None
     if args.fail_at is not None:
         if dp < 3:
-            print(f"NOTE: only {dp} device(s) visible - OptCC needs a DP "
-                  "ring of >= 3; failure injection disabled. Run with "
-                  "XLA_FLAGS=--xla_force_host_platform_device_count=8 "
-                  "to see the failover path.")
-        else:
-            injector = FailureInjector.nic_loss(
-                dp, args.fail_at, args.straggler % dp, args.ell,
-                repair_step=args.repair_at)
+            sys.exit(f"--fail-at needs a DP ring of >= 3 devices for OptCC; "
+                     f"{dp} visible. On CPU, add more with XLA_FLAGS="
+                     "--xla_force_host_platform_device_count=8.")
+        injector = FailureInjector.nic_loss(
+            dp, args.fail_at, args.straggler % dp, args.ell,
+            repair_step=args.repair_at)
 
     fault = FaultState(axis_size=dp)
     step_fn = make_dp_failover_step(model, mesh, opt, lr_fn, fault)
-    state = init_train_state(model, opt)
+    state = init_train_state(model, opt, mesh=mesh)
+    n_grad = sum(x.size for x in jax.tree.leaves(state.params))
     start = 0
     if args.ckpt_dir and latest_step(args.ckpt_dir) is not None:
         state, meta = restore(args.ckpt_dir, state)
+        state = jax.device_put(state, NamedSharding(mesh, P()))
         start = int(meta["step"])
         print(f"resumed from checkpoint at step {start}")
 
@@ -99,7 +112,8 @@ def main(argv=None):
             # rebuild mesh + step on the survivors, restore, continue.
             # (Batches stay deterministic: the pipeline is keyed on
             # (seed, step), not on the shard layout.)
-            ckpt = args.ckpt_dir or "/tmp/repro_elastic_ckpt"
+            ckpt = args.ckpt_dir or tempfile.mkdtemp(
+                prefix="repro_elastic_ckpt_")
             save(ckpt, step, state)
             dp = max(dp // 2, 1)
             devices = jax.devices()[:dp]
@@ -109,17 +123,16 @@ def main(argv=None):
             step_fn = make_dp_failover_step(model, mesh, opt, lr_fn,
                                             fault)
             state, _ = restore(ckpt, state)
-            state = jax.device_put(state)
+            state = jax.device_put(state, NamedSharding(mesh, P()))
             print(f"step {step}: NODE LOSS - resumed on {dp} devices "
                   f"(elastic reshard from checkpoint)")
         if injector is not None:
             new_fault = injector.at_step(step, fault)
             if new_fault != fault:
                 fault = new_fault
-                if fault.degraded:
-                    n_grad = sum(int(np.prod(x.shape)) for x in
-                                 jax.tree.leaves(state.params))
-                    plan = fault.plan(n_grad)
+                step_fn, plan = rebuild_step(model, mesh, opt, lr_fn,
+                                             fault, n_grad)
+                if plan is not None:
                     print(f"step {step}: DEGRADED (straggler="
                           f"{fault.straggler}, l={fault.ell}); planner "
                           f"chose {plan.algo}, predicted overhead "
@@ -127,8 +140,6 @@ def main(argv=None):
                           f"{plan.gen_seconds * 1e3:.2f} ms")
                 else:
                     print(f"step {step}: REPAIRED; back to native psum")
-                step_fn = make_dp_failover_step(model, mesh, opt, lr_fn,
-                                                fault)
         batch = jax.tree.map(jnp.asarray, data.batch(step))
         state, metrics = step_fn(state, batch)
         if step % args.log_every == 0 or step == args.steps - 1:

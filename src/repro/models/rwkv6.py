@@ -135,11 +135,8 @@ def _layer_parallel(cfg, bp, x):
         # Pallas wkv kernel: state stays in VMEM across the sequence
         # (forward/serving path; training uses the differentiable scan).
         from repro.kernels.wkv.ops import wkv as wkv_kernel
-        import jax as _jax
-        interp = _jax.default_backend() != "tpu"
         outs_bshd, wkv = wkv_kernel(
-            r, k, v, w, bp["bonus"].astype(jnp.float32).reshape(H, hd),
-            interpret=interp)
+            r, k, v, w, bp["bonus"].astype(jnp.float32).reshape(H, hd))
         outs = outs_bshd.swapaxes(0, 1)
     else:
         wkv, outs = chunked_scan(

@@ -1,2 +1,2 @@
-from repro.roofline.analysis import (Roofline, PEAK_FLOPS, HBM_BW, ICI_BW)
+from repro.roofline.analysis import ChipPeaks, PEAKS, Roofline, peaks_for
 from repro.roofline.hlo_parse import HloAnalysis, analyze_hlo

@@ -1,9 +1,8 @@
 """Roofline terms from compiled dry-run artifacts.
 
-Hardware model (TPU v5e target):
-  peak bf16 compute : 197 TFLOP/s per chip
-  HBM bandwidth     : 819 GB/s per chip
-  ICI link bandwidth: ~50 GB/s per link
+Hardware model: the per-chip peaks in PEAKS, keyed by the chip's
+`device_kind` (as `jax.devices()[0].device_kind` reports it). A kind that
+is not in the table is an error, never a default.
 
   compute term    = HLO_FLOPs / peak
   memory term     = HLO_bytes / HBM_bw
@@ -15,12 +14,28 @@ analysis of compiled.as_text(); see that module).
 from __future__ import annotations
 
 import dataclasses
-import re
-from typing import Optional
 
-PEAK_FLOPS = 197e12          # bf16 / chip
-HBM_BW = 819e9               # bytes/s / chip
-ICI_BW = 50e9                # bytes/s/link
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops: float                 # bf16 FLOP/s per chip
+    hbm_bw: float                # bytes/s per chip
+    ici_bw: float                # bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s
+# HBM, 1,600 Gbit/s chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": ChipPeaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> ChipPeaks:
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -38,18 +53,23 @@ class Roofline:
     bytes_collective: float       # per device
     model_flops: float            # 6*N*D (active params), whole step
     chips: int
+    device_kind: str              # key into PEAKS
+
+    @property
+    def peaks(self) -> ChipPeaks:
+        return peaks_for(self.device_kind)
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / self.peaks.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_hbm / HBM_BW
+        return self.bytes_hbm / self.peaks.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.bytes_collective / ICI_BW
+        return self.bytes_collective / self.peaks.ici_bw
 
     @property
     def dominant(self) -> str:
@@ -75,7 +95,7 @@ class Roofline:
         if self.bound_time == 0:
             return 0.0
         achieved = self.model_flops / self.bound_time / self.chips
-        return achieved / PEAK_FLOPS
+        return achieved / self.peaks.flops
 
     def to_dict(self) -> dict:
         return {
@@ -84,6 +104,7 @@ class Roofline:
             "collective_bytes_per_device": self.bytes_collective,
             "model_flops": self.model_flops,
             "chips": self.chips,
+            "device_kind": self.device_kind,
             "t_compute_s": self.t_compute,
             "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective,

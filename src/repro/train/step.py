@@ -15,9 +15,8 @@ Two distribution modes:
   produced per-shard and synchronized by an *explicit software collective*
   selected from the live FaultState: XLA psum when healthy,
   comms.optcc_allreduce when a member's link is degraded (the paper's
-  algorithm), optionally int8-compressed. At production scale each
-  tensor-parallel rank group runs exactly this program over its DP peers
-  (see DESIGN.md "Stage mapping").
+  algorithm). At production scale each tensor-parallel rank group runs
+  exactly this program over its DP peers.
 """
 from __future__ import annotations
 
@@ -26,7 +25,6 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -83,7 +81,8 @@ def param_pspec(path: str, leaf, cfg, mesh: Mesh) -> P:
             # dispatch buffer per layer - measured in SPerf).
             model_dim = ndim - 1 if name in ("e_gate", "e_up") \
                 else ndim - 2
-    if model_dim is not None and shape[model_dim] % mesh.shape["model"] == 0:
+    if (model_dim is not None and "model" in mesh.axis_names
+            and shape[model_dim] % mesh.shape["model"] == 0):
         spec[model_dim] = "model"
     # FSDP (ZeRO-3 style): shard the largest remaining dim over data.
     # Embedding-like tables are excluded: sharding their feature dim over
@@ -133,12 +132,9 @@ def make_gspmd_train_step(model: Model, mesh: Mesh,
                                     + x.shape[1:]), batch)
             zero = jax.tree.map(
                 lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
-            try:   # keep the grad accumulator sharded like the params
-                pshard = shardings_for_params(state.params, cfg, mesh)
-                zero = jax.tree.map(jax.lax.with_sharding_constraint,
-                                    zero, pshard)
-            except Exception:
-                pass
+            # keep the grad accumulator sharded like the params
+            zero = jax.tree.map(jax.lax.with_sharding_constraint, zero,
+                                shardings_for_params(state.params, cfg, mesh))
             (grads, loss), _ = lax.scan(micro, (zero, 0.0), mbs)
             grads = jax.tree.map(lambda g: g / num_microbatches, grads)
             loss = loss / num_microbatches
@@ -167,6 +163,11 @@ def make_dp_failover_step(model: Model, mesh: Mesh,
     Re-call this factory (re-jit) whenever `fault` changes - that is the
     NCCL-reinit analogue; the OptCC planner's closed form makes the new
     schedule cheap to produce.
+
+    The returned step donates its `TrainState` argument: rebind it to the
+    returned state (`state, m = step(state, batch)`) and do not read the
+    old one again. Build the state on `mesh` (`init_train_state(...,
+    mesh=mesh)`) so that it is already replicated where the step runs.
     """
     assert mesh.axis_names == ("data",)
     dp = mesh.shape["data"]
@@ -193,7 +194,7 @@ def make_dp_failover_step(model: Model, mesh: Mesh,
         out_specs=(P(), P(), P(), P()),
         check_vma=False)
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnums=0)
     def step(state: TrainState, batch: dict):
         new_params, new_opt, loss, gnorm = smapped(
             state.params, state.opt_state, state.step, batch)
@@ -203,8 +204,17 @@ def make_dp_failover_step(model: Model, mesh: Mesh,
     return step
 
 
-def init_train_state(model: Model, opt_cfg: AdamWConfig, seed: int = 0
-                     ) -> TrainState:
-    params = jax.jit(model.init)(jax.random.PRNGKey(seed))
-    return TrainState(params, init_state(params, opt_cfg),
-                      jnp.zeros((), jnp.int32))
+def init_train_state(model: Model, opt_cfg: AdamWConfig, seed: int = 0,
+                     mesh: Optional[Mesh] = None) -> TrainState:
+    """Fresh parameters from `seed` and zeroed optimizer state.
+
+    With `mesh`, the state is created replicated over all of its devices
+    (what make_dp_failover_step's P() specs expect); without, it lands on
+    the default device."""
+    def init(key):
+        params = model.init(key)
+        return TrainState(params, init_state(params, opt_cfg),
+                          jnp.zeros((), jnp.int32))
+    placement = {} if mesh is None else {
+        "out_shardings": NamedSharding(mesh, P())}
+    return jax.jit(init, **placement)(jax.random.PRNGKey(seed))

@@ -105,6 +105,7 @@ def main():
     print("compressed_psum OK")
 
     failover_equivalence()
+    chip_smoke_four_chip_phases()
 
     print("ALL-OK")
 
@@ -134,8 +135,8 @@ def failover_equivalence():
     degraded = make_dp_failover_step(model, mesh, opt, constant(1e-3),
                                      FaultState(axis_size=8, straggler=3,
                                                 ell=1.75))
-    s_h = init_train_state(model, opt, seed=7)
-    s_d = init_train_state(model, opt, seed=7)
+    s_h = init_train_state(model, opt, seed=7, mesh=mesh)
+    s_d = init_train_state(model, opt, seed=7, mesh=mesh)
     for i in range(3):
         b = jax.tree.map(jnp.asarray, data.batch(i))
         s_h, m_h = healthy(s_h, b)
@@ -145,6 +146,21 @@ def failover_equivalence():
                          s_h.params, s_d.params)
     assert max(jax.tree.leaves(diffs)) < 1e-5, diffs
     print("failover-equivalence OK")
+
+
+def chip_smoke_four_chip_phases():
+    """chip_smoke.py --chips 4's phases, at toy widths on 4 host devices."""
+    import sys
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), os.pardir))
+    import chip_smoke
+    from repro.configs import get_config
+    cfg = get_config("qwen3-1.7b").replace(
+        n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
+        d_ff=256, vocab_size=512, logits_chunk=32)
+    chip_smoke.allreduce_phase(chip_smoke.param_count(cfg), jax.devices()[:4])
+    chip_smoke.failover_phase(cfg, jax.devices()[:4], seq_len=64,
+                              per_device_batch=2)
+    print("chip_smoke four-chip phases OK")
 
 
 if __name__ == "__main__":

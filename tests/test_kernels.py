@@ -1,8 +1,9 @@
 """Pallas kernels vs pure-jnp oracles: shape/dtype sweeps in interpret mode.
 
 interpret=True executes the kernel body on CPU - validating the block
-decomposition, index maps, masking and online-softmax algebra; the Mosaic
-lowering itself requires a real TPU (documented in DESIGN.md).
+decomposition, index maps, masking and online-softmax algebra. The Mosaic
+lowering is checked by tests/test_tpu_compile.py (compiled for a described
+TPU, no chip needed) and run natively by chip_smoke.py on a TPU.
 """
 import jax.numpy as jnp
 import numpy as np

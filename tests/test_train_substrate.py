@@ -1,5 +1,8 @@
 """Training substrate: optimizer, schedules, data, checkpoint, train step."""
+import os
 import pathlib
+import subprocess
+import sys
 
 import jax
 import jax.numpy as jnp
@@ -146,3 +149,18 @@ def test_microbatched_step_matches_plain():
     d = jax.tree.map(lambda a, b: float(jnp.max(jnp.abs(a - b))),
                      s1.params, s2.params)
     assert max(jax.tree.leaves(d)) < 2e-5
+
+
+def test_fail_at_needs_three_devices():
+    """Asked to inject a NIC fault on fewer than 3 devices, the launcher
+    exits non-zero instead of training without the failover path."""
+    repo = pathlib.Path(__file__).resolve().parent.parent
+    env = dict(os.environ, PYTHONPATH=str(repo / "src"), JAX_PLATFORMS="cpu")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, "-m", "repro.launch.train", "--arch", "qwen3-1.7b",
+         "--smoke", "--steps", "2", "--fail-at", "1"],
+        capture_output=True, text=True, env=env, timeout=300, cwd=repo)
+    assert proc.returncode != 0
+    assert "needs a DP ring of >= 3 devices" in proc.stderr
+    assert "done" not in proc.stdout
