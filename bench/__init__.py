@@ -1,0 +1,1 @@
+"""The on-chip benchmark: see BENCHMARK.json and bench/run.py."""
