@@ -1,0 +1,9 @@
+"""sync_s: device seconds per step under the program's `grad_sync` scope
+(the gradient's sync, its copies and the loss's psum), the mean over the
+cell's chips (bench/scopes.py)."""
+from bench import scopes
+
+
+def read(ctx):
+    per_step = scopes.per_step(ctx)
+    return None if per_step is None else scopes.total(per_step, "grad_sync")

@@ -32,6 +32,9 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import named_scope as scope
+
+from repro.obs import scopes
 
 
 def _axis_size(axis_name: str) -> int:
@@ -111,6 +114,10 @@ def optcc_allreduce(x: jax.Array, axis_name: str, straggler: int,
     `straggler` and `axis_size` must be static (the program is re-jitted
     when the fault state changes - the moral equivalent of NCCL
     communicator re-initialization after failover).
+
+    Each stage runs under its `repro.obs.scopes` name: the pad under
+    `flatten`, then `S3`, `S1` and `S4` (one `hop<t>` scope per round),
+    `S2`, and the slice under `unflatten`.
     """
     p = axis_size
     if p < 3:
@@ -121,43 +128,52 @@ def optcc_allreduce(x: jax.Array, axis_name: str, straggler: int,
     peer = healthy[0]
     n = x.shape[0]
     pad = (-n) % ph
-    xp = jnp.pad(x, (0, pad))
+    with scope(scopes.FLATTEN):
+        xp = jnp.pad(x, (0, pad))
 
     # Stage "S3'" (ordering B): straggler -> peer; peer folds it in.
-    from_straggler = lax.ppermute(xp, axis_name, [(straggler, peer)])
-    xp = jnp.where(idx == peer, xp + from_straggler, xp)
+    with scope(scopes.S3):
+        from_straggler = lax.ppermute(xp, axis_name, [(straggler, peer)])
+        xp = jnp.where(idx == peer, xp + from_straggler, xp)
 
     # Stages S1/S4 on the healthy subring. Healthy member h = healthy[i]
     # plays ring position i; the straggler executes the same SPMD code but
     # is in no permutation pair, so it moves no data.
     hpos = jnp.where(idx > straggler, idx - 1, idx)      # ring position
-    chunks = xp.reshape(ph, -1)
     perm_h = [(healthy[i], healthy[(i + 1) % ph]) for i in range(ph)]
 
-    acc = chunks
-    for t in range(ph - 1):                               # reduce-scatter
-        send_ix = (hpos - t) % ph
-        send = lax.dynamic_index_in_dim(acc, send_ix, 0, False)
-        recv = lax.ppermute(send, axis_name, perm_h)
-        recv_ix = (hpos - t - 1) % ph
-        acc = lax.dynamic_update_index_in_dim(
-            acc, lax.dynamic_index_in_dim(acc, recv_ix, 0, False) + recv,
-            recv_ix, axis=0)
+    with scope(scopes.S1):                                # reduce-scatter
+        acc = chunks = xp.reshape(ph, -1)
+        for t in range(ph - 1):
+            with scope(scopes.hop(t)):
+                send_ix = (hpos - t) % ph
+                send = lax.dynamic_index_in_dim(acc, send_ix, 0, False)
+                recv = lax.ppermute(send, axis_name, perm_h)
+                recv_ix = (hpos - t - 1) % ph
+                acc = lax.dynamic_update_index_in_dim(
+                    acc, lax.dynamic_index_in_dim(acc, recv_ix, 0, False)
+                    + recv, recv_ix, axis=0)
 
-    own_ix = (hpos + 1) % ph
-    cur = lax.dynamic_index_in_dim(acc, own_ix, 0, False)
-    out = jnp.zeros_like(chunks)
-    out = lax.dynamic_update_index_in_dim(out, cur, own_ix, axis=0)
-    for t in range(ph - 1):                               # allgather
-        cur = lax.ppermute(cur, axis_name, perm_h)
-        cix = (hpos - t) % ph
-        out = lax.dynamic_update_index_in_dim(out, cur, cix, axis=0)
-    full = out.reshape(-1)
+    with scope(scopes.S4):                                # allgather
+        own_ix = (hpos + 1) % ph
+        cur = lax.dynamic_index_in_dim(acc, own_ix, 0, False)
+        out = jnp.zeros_like(chunks)
+        out = lax.dynamic_update_index_in_dim(out, cur, own_ix, axis=0)
+        for t in range(ph - 1):
+            with scope(scopes.hop(t)):
+                cur = lax.ppermute(cur, axis_name, perm_h)
+                cix = (hpos - t) % ph
+                out = lax.dynamic_update_index_in_dim(out, cur, cix, axis=0)
+        full = out.reshape(-1)
 
     # Stage "S2'": one healthy member returns the sum to the straggler.
-    to_straggler = lax.ppermute(full, axis_name, [(peer, straggler)])
-    full = jnp.where(idx == straggler, to_straggler, full)
-    return full[:n] if pad else full
+    with scope(scopes.S2):
+        to_straggler = lax.ppermute(full, axis_name, [(peer, straggler)])
+        full = jnp.where(idx == straggler, to_straggler, full)
+    if not pad:
+        return full
+    with scope(scopes.UNFLATTEN):
+        return full[:n]
 
 
 def optcc_allreduce_tree(tree, axis_name: str, straggler: int,
@@ -165,17 +181,21 @@ def optcc_allreduce_tree(tree, axis_name: str, straggler: int,
     """OptCC AllReduce over a pytree: flatten-concat, one collective, split.
 
     Concatenating all gradient leaves into one flat vector both matches the
-    paper's single-buffer model and amortizes the per-ppermute latency."""
+    paper's single-buffer model and amortizes the per-ppermute latency.
+    The concatenation runs under the `flatten` scope, the split under
+    `unflatten`."""
     leaves, treedef = jax.tree.flatten(tree)
     sizes = [leaf.size for leaf in leaves]
-    flat = jnp.concatenate([leaf.reshape(-1).astype(jnp.float32)
-                            for leaf in leaves])
+    with scope(scopes.FLATTEN):
+        flat = jnp.concatenate([leaf.reshape(-1).astype(jnp.float32)
+                                for leaf in leaves])
     summed = optcc_allreduce(flat, axis_name, straggler, axis_size)
     outs, off = [], 0
-    for leaf, size in zip(leaves, sizes):
-        outs.append(summed[off:off + size].reshape(leaf.shape)
-                    .astype(leaf.dtype))
-        off += size
+    with scope(scopes.UNFLATTEN):
+        for leaf, size in zip(leaves, sizes):
+            outs.append(summed[off:off + size].reshape(leaf.shape)
+                        .astype(leaf.dtype))
+            off += size
     return jax.tree.unflatten(treedef, outs)
 
 

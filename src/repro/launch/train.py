@@ -6,7 +6,9 @@ Runs data-parallel training with:
     event the OptCC planner produces the new collective schedule and the
     train step is re-built (re-jit), mirroring NCCL communicator re-init,
   * straggler mitigation = the paper's algorithm (degraded mode syncs
-    gradients with optcc_allreduce instead of psum).
+    gradients with optcc_allreduce instead of psum);
+  * on each switch, the plan's time and the new step's trace / lower /
+    compile (or cache load) seconds from `repro.obs.compiles`.
 
 Works on any device count >= 1 (the DP axis is however many devices jax
 sees; force more with XLA_FLAGS=--xla_force_host_platform_device_count=8).
@@ -33,6 +35,7 @@ from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLM
 from repro.launch.cache import enable_compile_cache
 from repro.models import build_model
+from repro.obs import compiles
 from repro.optim import AdamWConfig
 from repro.optim.schedules import warmup_stable_decay
 from repro.train import init_train_state, make_dp_failover_step
@@ -126,10 +129,12 @@ def main(argv=None):
             state = jax.device_put(state, NamedSharding(mesh, P()))
             print(f"step {step}: NODE LOSS - resumed on {dp} devices "
                   f"(elastic reshard from checkpoint)")
+        built = None
         if injector is not None:
             new_fault = injector.at_step(step, fault)
             if new_fault != fault:
                 fault = new_fault
+                built = compiles.snapshot()
                 step_fn, plan = rebuild_step(model, mesh, opt, lr_fn,
                                              fault, n_grad)
                 if plan is not None:
@@ -142,6 +147,13 @@ def main(argv=None):
                     print(f"step {step}: REPAIRED; back to native psum")
         batch = jax.tree.map(jnp.asarray, data.batch(step))
         state, metrics = step_fn(state, batch)
+        if built is not None:             # the new program's first step
+            c = compiles.since(built)
+            print(f"step {step}: new step built in {c['total_s']:.2f} s: "
+                  f"trace {c['trace_s']:.2f} s, lower {c['lower_s']:.2f} s, "
+                  f"compile {c['compile_s']:.2f} s ({int(c['cache_loads'])} "
+                  f"of {int(c['compiles'])} programs from the cache in "
+                  f"{c['cache_load_s']:.2f} s)")
         if step % args.log_every == 0 or step == args.steps - 1:
             print(f"step {step:5d} loss {float(metrics['loss']):.4f} "
                   f"gnorm {float(metrics['grad_norm']):.3f} "

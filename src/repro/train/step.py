@@ -26,11 +26,13 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax import named_scope as scope
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.comms import optcc_allreduce_tree
 from repro.comms.fault import FaultState
 from repro.models.api import Model
+from repro.obs import scopes
 from repro.optim import AdamWConfig, init_state, update
 from repro.train.state import TrainState
 
@@ -164,6 +166,11 @@ def make_dp_failover_step(model: Model, mesh: Mesh,
     NCCL-reinit analogue; the OptCC planner's closed form makes the new
     schedule cheap to produce.
 
+    The step's work runs under the `repro.obs.scopes` names: `model` (loss
+    and gradient), `grad_sync` (the sync, the division by the DP width and
+    the loss's psum under `loss`) and `optimizer`; the healthy sync is
+    `grad_sync/psum`, the degraded one OptCC's stages.
+
     The returned step donates its `TrainState` argument: rebind it to the
     returned state (`state, m = step(state, batch)`) and do not read the
     old one again. Build the state on `mesh` (`init_train_state(...,
@@ -173,19 +180,24 @@ def make_dp_failover_step(model: Model, mesh: Mesh,
     dp = mesh.shape["data"]
 
     def body(params, opt_state, step_no, batch):
-        loss, grads = jax.value_and_grad(model.loss)(params, batch)
-        if fault.degraded:
-            grads = optcc_allreduce_tree(grads, "data",
-                                         fault.straggler, dp)
-            grads = jax.tree.map(lambda g: g / dp, grads)
-            loss = lax.psum(loss, "data") / dp
-        else:
-            grads = jax.tree.map(lambda g: lax.psum(g, "data") / dp,
-                                 grads)
-            loss = lax.psum(loss, "data") / dp
-        lr = lr_fn(step_no)
-        new_params, new_opt, gnorm = update(params, grads, opt_state, lr,
-                                            opt_cfg)
+        with scope(scopes.MODEL):
+            loss, grads = jax.value_and_grad(model.loss)(params, batch)
+        with scope(scopes.GRAD_SYNC):
+            if fault.degraded:
+                grads = optcc_allreduce_tree(grads, "data",
+                                             fault.straggler, dp)
+                with scope(scopes.UNFLATTEN):
+                    grads = jax.tree.map(lambda g: g / dp, grads)
+            else:
+                with scope(scopes.PSUM):
+                    grads = jax.tree.map(lambda g: lax.psum(g, "data") / dp,
+                                         grads)
+            with scope(scopes.LOSS):
+                loss = lax.psum(loss, "data") / dp
+        with scope(scopes.OPTIMIZER):
+            lr = lr_fn(step_no)
+            new_params, new_opt, gnorm = update(params, grads, opt_state,
+                                                lr, opt_cfg)
         return new_params, new_opt, loss, gnorm
 
     smapped = jax.shard_map(

@@ -56,3 +56,5 @@ def test_failure_injection_path():
     assert proc.returncode == 0, proc.stdout + proc.stderr
     assert "DEGRADED" in proc.stdout and "optcc-single" in proc.stdout
     assert "REPAIRED; back to native psum" in proc.stdout
+    # each switch reports its new step's trace / lower / compile split
+    assert proc.stdout.count("new step built in") == 2
