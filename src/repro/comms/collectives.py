@@ -37,6 +37,12 @@ from jax import named_scope as scope
 from repro.obs import scopes
 
 
+# OptCC pads its vector to a multiple of (subring size) * CHUNK_ALIGN, so that
+# each subring chunk starts on a whole tile of a 1-D f32 vector (TPU lays one
+# out in tiles of 1024 elements).
+CHUNK_ALIGN = 1024
+
+
 def _axis_size(axis_name: str) -> int:
     return lax.axis_size(axis_name)
 
@@ -115,6 +121,15 @@ def optcc_allreduce(x: jax.Array, axis_name: str, straggler: int,
     when the fault state changes - the moral equivalent of NCCL
     communicator re-initialization after failover).
 
+    The healthy subring keeps its data flat, in no (ph, n/ph) buffer. S1
+    carries a running partial: at hop t a member adds the partial it
+    receives to its own chunk (hpos - t - 1) % ph, read by a dynamic
+    slice of the padded vector, and sends the sum on; the additions, and
+    their order, are those of the classic ring. S4 writes the reduced
+    chunk and each one it receives into that vector, dead once S1 is
+    done: chunk k at offset k * c. The pad reaches a multiple of
+    ph * CHUNK_ALIGN, so every chunk starts on a whole tile.
+
     Each stage runs under its `repro.obs.scopes` name: the pad under
     `flatten`, then `S3`, `S1` and `S4` (one `hop<t>` scope per round),
     `S2`, and the slice under `unflatten`.
@@ -127,7 +142,8 @@ def optcc_allreduce(x: jax.Array, axis_name: str, straggler: int,
     ph = p - 1
     peer = healthy[0]
     n = x.shape[0]
-    pad = (-n) % ph
+    pad = (-n) % (ph * CHUNK_ALIGN)
+    c = (n + pad) // ph                                   # chunk length
     with scope(scopes.FLATTEN):
         xp = jnp.pad(x, (0, pad))
 
@@ -142,29 +158,24 @@ def optcc_allreduce(x: jax.Array, axis_name: str, straggler: int,
     hpos = jnp.where(idx > straggler, idx - 1, idx)      # ring position
     perm_h = [(healthy[i], healthy[(i + 1) % ph]) for i in range(ph)]
 
+    def chunk(k):
+        return lax.dynamic_slice_in_dim(xp, k * c, c)
+
     with scope(scopes.S1):                                # reduce-scatter
-        acc = chunks = xp.reshape(ph, -1)
+        part = chunk(hpos)
         for t in range(ph - 1):
             with scope(scopes.hop(t)):
-                send_ix = (hpos - t) % ph
-                send = lax.dynamic_index_in_dim(acc, send_ix, 0, False)
-                recv = lax.ppermute(send, axis_name, perm_h)
-                recv_ix = (hpos - t - 1) % ph
-                acc = lax.dynamic_update_index_in_dim(
-                    acc, lax.dynamic_index_in_dim(acc, recv_ix, 0, False)
-                    + recv, recv_ix, axis=0)
+                recv = lax.ppermute(part, axis_name, perm_h)
+                part = chunk((hpos - t - 1) % ph) + recv
 
     with scope(scopes.S4):                                # allgather
-        own_ix = (hpos + 1) % ph
-        cur = lax.dynamic_index_in_dim(acc, own_ix, 0, False)
-        out = jnp.zeros_like(chunks)
-        out = lax.dynamic_update_index_in_dim(out, cur, own_ix, axis=0)
+        full = lax.dynamic_update_slice_in_dim(xp, part,
+                                               (hpos + 1) % ph * c, 0)
         for t in range(ph - 1):
             with scope(scopes.hop(t)):
-                cur = lax.ppermute(cur, axis_name, perm_h)
-                cix = (hpos - t) % ph
-                out = lax.dynamic_update_index_in_dim(out, cur, cix, axis=0)
-        full = out.reshape(-1)
+                part = lax.ppermute(part, axis_name, perm_h)
+                full = lax.dynamic_update_slice_in_dim(
+                    full, part, (hpos - t) % ph * c, 0)
 
     # Stage "S2'": one healthy member returns the sum to the straggler.
     with scope(scopes.S2):

@@ -2,7 +2,8 @@
 
 Must set XLA_FLAGS before importing jax - which is why these checks cannot
 run inside the main pytest process (smoke tests there must see 1 device).
-Prints 'ALL-OK' on success; any assertion failure raises.
+Prints 'ALL-OK' on success; any assertion failure raises. With the
+argument `bitexact` it runs only `optcc_bit_exact` and prints 'BITEXACT-OK'.
 """
 import os
 
@@ -10,7 +11,8 @@ os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=8 "
     + os.environ.get("XLA_FLAGS", ""))
 
-import functools  # noqa: E402
+import sys  # noqa: E402
+
 import numpy as np  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
@@ -110,6 +112,51 @@ def main():
     print("ALL-OK")
 
 
+def optcc_subring_model(xs: np.ndarray, straggler: int) -> np.ndarray:
+    """OptCC's sum of the rows of `xs` (p, n) in float32, in the order of
+    its additions: the straggler's row joins its peer's first (peer +
+    straggler); then chunk k's partial starts at ring position k and each
+    next position adds its own chunk to the partial it receives."""
+    from repro.comms.collectives import CHUNK_ALIGN
+    p, n = xs.shape
+    healthy = [r for r in range(p) if r != straggler]
+    ph = p - 1
+    pad = (-n) % (ph * CHUNK_ALIGN)
+    c = (n + pad) // ph
+    v = {h: np.pad(xs[h], (0, pad)) for h in healthy}
+    v[healthy[0]] = v[healthy[0]] + np.pad(xs[straggler], (0, pad))
+    out = []
+    for k in range(ph):
+        part = v[healthy[k]][k * c:(k + 1) * c]
+        for j in range(1, ph):
+            part = v[healthy[(k + j) % ph]][k * c:(k + 1) * c] + part
+        out.append(part)
+    return np.concatenate(out)[:n]
+
+
+def optcc_bit_exact():
+    """optcc_allreduce == optcc_subring_model, bit for bit, on every member
+    and at every straggler position: p = 8 (ph = 7), and p = 4 (ph = 3)
+    at n = 1,000,003, which is no multiple of the pad's."""
+    rng = np.random.default_rng(14)
+    for p, n in ((8, 50_001), (4, 1_000_003)):
+        mesh = Mesh(np.array(jax.devices()[:p]), ("dp",))
+        x = rng.standard_normal((p, n)).astype(np.float32)
+        for straggler in range(p):
+            def f(xs, straggler=straggler, p=p):
+                return optcc_allreduce(xs[0], "dp", straggler, p)[None]
+            out = np.asarray(jax.jit(shard_map(
+                f, mesh=mesh, in_specs=P("dp"), out_specs=P("dp")))(x))
+            want = optcc_subring_model(x, straggler)
+            assert out.dtype == want.dtype == np.float32
+            for r in range(p):
+                np.testing.assert_array_equal(out[r], want,
+                                              err_msg=f"p={p} s={straggler}")
+            # the model is no sum in another order: it differs from one
+            assert not np.array_equal(want, x.sum(0, dtype=np.float32))
+    print("BITEXACT-OK")
+
+
 def failover_equivalence():
     """Degraded-mode (OptCC) training == healthy (psum) training, bitwise
     up to fp tolerance: 3 steps each on 8 DP shards."""
@@ -164,4 +211,7 @@ def chip_smoke_four_chip_phases():
 
 
 if __name__ == "__main__":
-    main()
+    if sys.argv[1:] == ["bitexact"]:
+        optcc_bit_exact()
+    else:
+        main()

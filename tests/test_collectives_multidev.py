@@ -25,6 +25,21 @@ def test_collectives_on_8_devices():
     assert "ALL-OK" in proc.stdout
 
 
+def test_optcc_allreduce_is_bit_exact_on_8_devices():
+    """OptCC's subring adds in the ring's order, bit for bit, at every
+    straggler position of p = 8 and of p = 4 (tests/multidev_driver.py's
+    `bitexact` mode)."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = str(REPO / "src")
+    env.pop("XLA_FLAGS", None)
+    proc = subprocess.run(
+        [sys.executable, str(REPO / "tests" / "multidev_driver.py"),
+         "bitexact"],
+        capture_output=True, text=True, env=env, timeout=300)
+    assert proc.returncode == 0, proc.stdout + "\n" + proc.stderr
+    assert "BITEXACT-OK" in proc.stdout
+
+
 @pytest.mark.slow
 def test_elastic_node_loss_rescale():
     """Train on 8 virtual devices, lose half at step 4, continue on 4."""

@@ -184,9 +184,9 @@ def healthy_text(topo):
 
 
 def _instructions(text: str, every: bool = False) -> dict:
-    """instruction -> (op_name, opcode), for the computations that run as
-    operations (fused computations, reducers and loop conditions left
-    out unless `every`)."""
+    """instruction -> (op_name, opcode, shape), for the computations that
+    run as operations (fused computations, reducers and loop conditions
+    left out unless `every`)."""
     comps: dict = {}
     inner: set = set()
     comp = None
@@ -205,9 +205,9 @@ def _instructions(text: str, every: bool = False) -> dict:
                 depth -= ch == ")"
                 if depth == 0:
                     break
-            rest = rest[i + 1:]
+            shape, rest = rest[:i + 1], rest[i + 1:]
         else:
-            rest = rest.partition(" ")[2]
+            shape, _, rest = rest.partition(" ")
         opcode = re.match(r"\s*([\w\-]+)\(", rest).group(1)
         if "AllocateBuffer" in line:
             opcode = "after-all"          # reserves memory, does no work
@@ -216,13 +216,13 @@ def _instructions(text: str, every: bool = False) -> dict:
                 r"(?:calls|to_apply|condition)=%([\w.\-]+)", line))
         op = OP_NAME.search(line)
         comps.setdefault(comp, {})[m.group(1)] = (op.group(1) if op else "",
-                                                  opcode)
+                                                  opcode, shape)
     return {n: v for c, instrs in comps.items() if every or c not in inner
             for n, v in instrs.items()}
 
 
 def test_degraded_step_names_every_stage(degraded_text):
-    paths = {_scope(op) for op, _ in
+    paths = {_scope(op) for op, *_ in
              _instructions(degraded_text, every=True).values()} - {None}
     want = {"model/forward", "model/backward", scopes.OPTIMIZER}
     want |= {f"{scopes.GRAD_SYNC}/{s}" for s in
@@ -235,7 +235,7 @@ def test_degraded_step_names_every_stage(degraded_text):
 
 def test_every_collective_permute_lies_in_an_optcc_stage(degraded_text):
     instrs = _instructions(degraded_text)
-    permutes = [(n, op) for n, (op, code) in instrs.items()
+    permutes = [(n, op) for n, (op, code, _) in instrs.items()
                 if code.startswith("collective-permute")]
     assert permutes
     stages = {f"{scopes.GRAD_SYNC}/{s}" for s in
@@ -247,13 +247,36 @@ def test_every_collective_permute_lies_in_an_optcc_stage(degraded_text):
 
 def test_healthy_all_reduce_lies_under_psum(healthy_text):
     instrs = _instructions(healthy_text)
-    paths = [_scope(op) for op, code in instrs.values()
+    paths = [_scope(op) for op, code, _ in instrs.values()
              if code.startswith("all-reduce")]
     sync = f"{scopes.GRAD_SYNC}/{scopes.PSUM}"
     assert sync in paths
     assert set(paths) <= {sync, f"{scopes.GRAD_SYNC}/{scopes.LOSS}"}
     assert not any(code.startswith("collective-permute")
-                   for _, code in instrs.values())
+                   for _, code, _ in instrs.values())
+
+
+F32_DIMS = re.compile(r"\bf32\[([\d,]*)\]")
+
+
+def test_optcc_subring_holds_its_data_flat(degraded_text):
+    """S1 and S4 make no f32 array of rank 2 or more, and each of their
+    dynamic-update-slices writes a rank-1 buffer: the subring keeps no
+    (ph, n/ph) stack, whose row updates the TPU pads and relayouts."""
+    stages = {f"{scopes.GRAD_SYNC}/{s}" for s in (scopes.S1, scopes.S4)}
+    ops = [(n, code, shape) for n, (op, code, shape)
+           in _instructions(degraded_text, every=True).items()
+           if "/".join((_scope(op) or "").split("/")[:2]) in stages]
+    assert ops
+    ranks = {n: [len(d.split(",")) if d else 0
+                 for d in F32_DIMS.findall(shape)]
+             for n, _, shape in ops}
+    stacked = [(n, shape) for n, _, shape in ops
+               if max(ranks[n], default=0) > 1]
+    assert not stacked, stacked
+    updates = [n for n, code, _ in ops if code == "dynamic-update-slice"]
+    assert updates
+    assert all(ranks[n] == [1] for n in updates), updates
 
 
 @pytest.mark.parametrize("which", ["degraded", "healthy"])
@@ -263,7 +286,7 @@ def test_few_instructions_are_left_without_a_scope(request, which):
     under 10% of them; read from the program's own op_names, nothing
     inherited."""
     text = request.getfixturevalue(f"{which}_text")
-    named = [op for op, code in _instructions(text).values()
+    named = [op for op, code, _ in _instructions(text).values()
              if code not in NO_WORK and op]
     left = [op for op in named if _scope(op) is None]
     assert len(named) > 200
