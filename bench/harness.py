@@ -17,7 +17,6 @@ from __future__ import annotations
 
 import collections
 import dataclasses
-import importlib
 import json
 import pathlib
 import time
@@ -30,7 +29,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 from jax.profiler import TraceAnnotation as span
 
-from bench import compare, weights, workload
+from bench import compare, refs, weights, workload
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 LAG = 2            # steps in flight before the host waits for the oldest
@@ -81,21 +80,23 @@ def load_cell(name: str) -> Cell:
 
 
 def model_config(conf: dict, name: str):
-    """The program's ModelConfig for a configuration file."""
+    """The program's ModelConfig for a configuration file: family "dense",
+    the dtypes, and the fields that the reference module reads from the
+    published keys (`program_settings`), then the file's optional
+    `program` object over them, whose keys are ModelConfig fields."""
     from repro.configs.base import ModelConfig
-    H = conf["num_attention_heads"]
-    return ModelConfig(
-        name=name, family="dense", n_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"], n_heads=H,
-        n_kv_heads=conf["num_key_value_heads"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        head_dim=conf["hidden_size"] // H,
-        rope_theta=float(conf["rope_theta"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        param_dtype=conf["dtypes"]["params"],
-        compute_dtype=conf["dtypes"]["compute"],
-        moment_dtype=conf["dtypes"]["adam_moments"])
+    program = conf.get("program", {})
+    known = {f.name for f in dataclasses.fields(ModelConfig)}
+    unknown = sorted(set(program) - known)
+    if unknown:
+        raise ValueError(f"{name}: 'program' sets no ModelConfig field "
+                         f"{', '.join(unknown)}")
+    return ModelConfig(**{
+        "name": name, "family": "dense",
+        "param_dtype": conf["dtypes"]["params"],
+        "compute_dtype": conf["dtypes"]["compute"],
+        "moment_dtype": conf["dtypes"]["adam_moments"],
+        **refs.module(conf).program_settings(conf), **program})
 
 
 # ----------------------------------------------------------------------------
@@ -273,7 +274,7 @@ def run_window(prog: Program, sched: workload.Schedule, live: Live,
 def reference(cell: Cell, seed: int, steps: int, device, fp8: bool = False):
     """(Readings, parameters before step 1) of the plain reference over
     the first `steps` steps, on `device`, from its own weights."""
-    ref = importlib.import_module(f"bench.refs.{cell.conf['reference']}")
+    ref = refs.module(cell.conf)
     conf, mix = cell.conf, cell.mix
     one = SingleDeviceSharding(device)
     params = weights.make(conf, seed, one)

@@ -6,9 +6,11 @@ final RMSNorm and the vocabulary head (tied to the embedding or not). The
 loss is the mean next-token cross-entropy over every token of the batch.
 
 Everything is float32 at the highest matmul precision. It imports nothing
-of the program. It reads weights laid out as the benchmark makes them
-(`bench/weights.py`): stacked per layer, norm weights stored as the offset
-from 1.
+of the program. It reads weights laid out as `shapes` gives them and
+`bench/weights.py` makes them: stacked per layer, norm weights stored as
+the offset from 1. `flops_per_token` is PaLM's count for this layout, and
+`program_settings` the program's settings for it (`bench/refs/__init__.py`
+lists what a reference module gives the harness).
 
 `fp8=True` is the lower-precision control: every product with a weight
 (and the vocabulary head) takes operands rounded to float8 e4m3 with one
@@ -33,6 +35,68 @@ from jax import lax
 HIGHEST = lax.Precision.HIGHEST
 Q_BLOCK = 512          # query rows per attention block
 T_BLOCK = 512          # tokens per block of the vocabulary head
+
+
+# ----------------------------------------------------------------------------
+# what the harness reads of this kind of model
+# ----------------------------------------------------------------------------
+
+def shapes(conf: dict) -> dict:
+    """{name: shape} of every leaf, `blocks/<leaf>` stacked over layers."""
+    L = conf["num_hidden_layers"]
+    d, f, V = conf["hidden_size"], conf["intermediate_size"], conf["vocab_size"]
+    H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
+    hd = d // H
+    out = {"embed": (V, d), "final_norm": (d,)}
+    if not conf["tie_word_embeddings"]:
+        out["lm_head"] = (d, V)
+    out.update({
+        "blocks/ln1": (L, d), "blocks/wq": (L, d, H * hd),
+        "blocks/wk": (L, d, KV * hd), "blocks/wv": (L, d, KV * hd),
+        "blocks/wo": (L, H * hd, d), "blocks/ln2": (L, d),
+        "blocks/w_gate": (L, d, f), "blocks/w_up": (L, d, f),
+        "blocks/w_down": (L, f, d),
+    })
+    return out
+
+
+def matmul_params(conf: dict) -> int:
+    """N: weights that enter a matrix product, per token, forward."""
+    d, f, V = conf["hidden_size"], conf["intermediate_size"], conf["vocab_size"]
+    H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
+    hd = d // H
+    per_layer = d * H * hd + 2 * d * KV * hd + H * hd * d + 3 * d * f
+    return conf["num_hidden_layers"] * per_layer + d * V
+
+
+def flops_per_token(conf: dict, seq_len: int) -> int:
+    """Forward and backward FLOPs of one token at sequence length T, by
+    PaLM's formula (Chowdhery et al. 2022, appendix B): 6N + 12 L H Q T,
+    where N is `matmul_params` (every weight that enters a matrix product,
+    the vocabulary head included, the embedding lookup not) and the second
+    term is attention's two products over the whole sequence T. Recomputed
+    work does not count."""
+    L, H = conf["num_hidden_layers"], conf["num_attention_heads"]
+    Q = conf["hidden_size"] // H
+    return 6 * matmul_params(conf) + 12 * L * H * Q * seq_len
+
+
+def program_settings(conf: dict) -> dict:
+    """The program's ModelConfig fields for the decoder's published keys."""
+    H = conf["num_attention_heads"]
+    return dict(
+        n_layers=conf["num_hidden_layers"], d_model=conf["hidden_size"],
+        n_heads=H, n_kv_heads=conf["num_key_value_heads"],
+        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
+        head_dim=conf["hidden_size"] // H,
+        rope_theta=float(conf["rope_theta"]),
+        tie_embeddings=bool(conf["tie_word_embeddings"]),
+        norm_eps=float(conf["rms_norm_eps"]))
+
+
+# ----------------------------------------------------------------------------
+# the reference
+# ----------------------------------------------------------------------------
 
 
 def _scaled_round(x, dtype, top):

@@ -20,17 +20,26 @@ TOY = {"num_hidden_layers": 2, "hidden_size": 64, "intermediate_size": 128,
        "vocab_size": 256}
 
 
+def toy_conf(conf: dict, **over) -> dict:
+    """A configuration file at toy widths: TOY, then the file's optional
+    `toy` object (a MoE file's toy expert count, MHA's toy heads), then
+    `over`."""
+    return {**conf, **TOY, **conf.get("toy", {}), **over}
+
+
 def toy(cell, seq_len: int = 64, **conf):
     """The cell at toy widths and a short sequence, everything else as
     its files say."""
     import dataclasses
-    c = dict(cell.conf)
-    c.update(TOY)
-    if cell.conf["num_attention_heads"] == cell.conf["num_key_value_heads"]:
-        c["num_key_value_heads"] = c["num_attention_heads"]   # no GQA
-    c.update(conf)
     mix = dict(cell.mix, seq_len=seq_len)
-    return dataclasses.replace(cell, conf=c, mix=mix)
+    return dataclasses.replace(cell, conf=toy_conf(cell.conf, **conf),
+                               mix=mix)
+
+
+def config_file(config: str) -> dict:
+    import json
+    return json.loads((ROOT / "bench" / "configs" / f"{config}.json")
+                      .read_text())
 
 
 CONFIGS = sorted(p.stem for p in (ROOT / "bench" / "configs").glob("*.json"))
@@ -47,8 +56,7 @@ def mix_cell(mix: str, config: str = "minicpm-2b"):
     from bench import harness
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
     first = harness.load_cell(spec["workloads"][0]["name"])
-    conf = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
-                      .read_text())
+    conf = config_file(config)
     m = json.loads((ROOT / "bench" / "traffic" / f"{mix}.json").read_text())
     e2e = [{"name": "tokens_per_s", "unit": "tokens/s"},
            {"name": "setup_s", "unit": "s"}]

@@ -13,9 +13,10 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from conftest import CONFIGS, ROOT
-from bench import harness, weights
-from bench.refs import dense_decoder
+from conftest import CONFIGS, ROOT, config_file
+from bench import harness, refs, weights
+from repro.comms.collectives import CHUNK_ALIGN
+
 HBM = 15.75e9        # what the compiler lets one v5e program use
 
 
@@ -38,8 +39,7 @@ def topo():
 
 def _cell(config: str, chips: int):
     """`config` at its file's widths and depth under the four-chip mix."""
-    conf = json.loads((ROOT / "bench" / "configs" / f"{config}.json")
-                      .read_text())
+    conf = config_file(config)
     mix = json.loads((ROOT / "bench" / "traffic" / "dp4.degraded.json")
                      .read_text())
     return harness.Cell(config, chips, config, conf, "dp4.degraded", mix,
@@ -69,7 +69,7 @@ def test_four_chip_step_compiles_at_file_depth(topo, config, degraded):
     text = compiled.as_text()
     assert ("collective-permute" in text) == degraded
     assert weights.count(cell.conf) == cell.conf["gradient_elements"]
-    pad = (-cell.conf["gradient_elements"]) % 3
+    pad = (-cell.conf["gradient_elements"]) % (3 * CHUNK_ALIGN)
     assert pad == cell.conf["optcc_pad"]
 
 
@@ -84,10 +84,9 @@ def test_reference_fits_one_chip(topo, config):
     rows = cell.mix["dp"] * cell.mix["rows_per_chip"]
     tok = jax.ShapeDtypeStruct((rows, cell.mix["seq_len"]), jnp.int32,
                                sharding=one)
-    items = tuple(sorted((k, v) for k, v in cell.conf.items()
-                         if isinstance(v, (int, float, bool, str))))
-    m = dense_decoder._loss_and_grad.lower(items, False, params, tok,
-                                           tok).compile().memory_analysis()
+    ref = refs.module(cell.conf)
+    m = jax.jit(lambda p, t, y: ref.loss_and_grad(cell.conf, p, t, y)).lower(
+        params, tok, tok).compile().memory_analysis()
     total = (m.argument_size_in_bytes + m.temp_size_in_bytes
              + m.output_size_in_bytes)
     assert total < 0.8 * HBM

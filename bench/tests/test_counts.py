@@ -1,4 +1,5 @@
-"""bench/counts.py against XLA's own count and against the ring's bytes."""
+"""bench/counts.py, and dense_decoder's FLOP count, against XLA's own count
+and against the ring's bytes."""
 import dataclasses
 import re
 
@@ -9,8 +10,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from conftest import CONFIGS, mix_cell
+from conftest import CONFIGS, config_file, mix_cell
 from bench import counts, harness, weights
+from bench.refs import dense_decoder
 
 # Elementwise work that PaLM's formula leaves out and XLA counts, as a
 # bound per unit: the optimizer's clip and AdamW per parameter, and per
@@ -18,6 +20,8 @@ from bench import counts, harness, weights
 # of the vocabulary, SwiGLU's gate, casts) forward and backward.
 PER_PARAM = 60
 PER_ACTIVATION = 60
+DENSE = [c for c in CONFIGS
+         if config_file(c)["reference"] == "dense_decoder"]
 
 
 def _activations_per_token(conf: dict, seq_len: int) -> int:
@@ -27,7 +31,7 @@ def _activations_per_token(conf: dict, seq_len: int) -> int:
             + 2 * conf["vocab_size"])
 
 
-@pytest.mark.parametrize("config", CONFIGS)
+@pytest.mark.parametrize("config", DENSE)
 @pytest.mark.parametrize("seq_len", [128, 256])
 def test_flops_per_token_against_cost_analysis(config, seq_len):
     """At toy widths, with the layers and the loss unrolled so that XLA
@@ -47,7 +51,7 @@ def test_flops_per_token_against_cost_analysis(config, seq_len):
     cost = compiled.cost_analysis()
     cost = cost[0] if isinstance(cost, list) else cost
     tokens = prog.rows * seq_len
-    formula = counts.flops_per_token(cell.conf, seq_len) * tokens
+    formula = dense_decoder.flops_per_token(cell.conf, seq_len) * tokens
     slack = (PER_PARAM * weights.count(cell.conf)
              + PER_ACTIVATION * _activations_per_token(cell.conf, seq_len)
              * tokens)
@@ -60,8 +64,12 @@ def test_flops_per_token_by_hand():
             "vocab_size": 10}
     # per layer: q 8*8, k and v 2*8*4, o 8*8, gate/up/down 3*8*16
     n = 2 * (64 + 64 + 64 + 384) + 8 * 10
-    assert counts.matmul_params(conf) == n
-    assert counts.flops_per_token(conf, 32) == 6 * n + 12 * 2 * 2 * 4 * 32
+    assert dense_decoder.matmul_params(conf) == n
+    assert dense_decoder.flops_per_token(conf, 32) == (6 * n
+                                                       + 12 * 2 * 2 * 4 * 32)
+    # bench/counts.py counts by the module that `reference` names
+    assert counts.flops_per_token(dict(conf, reference="dense_decoder"),
+                                  32) == 6 * n + 12 * 2 * 2 * 4 * 32
 
 
 @pytest.mark.parametrize("n", [4096, 12288])

@@ -1,36 +1,49 @@
 """Weights made from the seed, on the device, in one jitted call.
 
-The layout is the dense decoder's parameter tree as the program's train
-step and the plain reference both read it: `embed` (V, d), `blocks` with
-every leaf stacked over the layers, `final_norm`, and `lm_head` (d, V)
-when the head is not tied. Matrices are N(0, 0.02); norm weights are the
-offset from 1, so 0. Everything is made in the configuration's parameter
-type.
+The layout is the configuration's reference module's (`shapes`, see
+`bench/refs/__init__.py`): the parameter tree as the program's train step
+and the plain reference both read it, every `blocks/<leaf>` stacked over
+the layers. The rule for a leaf: matrices are N(0, 0.02), norm weights are
+the offset from 1, so 0, and everything is made in the configuration's
+parameter type; the module's optional `leaf_rules` gives the leaves that
+differ (a MoE router held in float32).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from bench import refs
+
 STD = 0.02
+INITS = ("normal", "zeros")
 
 
 def shapes(conf: dict) -> dict:
     """{name: shape} of every leaf, `blocks/<leaf>` stacked over layers."""
-    L = conf["num_hidden_layers"]
-    d, f, V = conf["hidden_size"], conf["intermediate_size"], conf["vocab_size"]
-    H, KV = conf["num_attention_heads"], conf["num_key_value_heads"]
-    hd = d // H
-    out = {"embed": (V, d), "final_norm": (d,)}
-    if not conf["tie_word_embeddings"]:
-        out["lm_head"] = (d, V)
-    out.update({
-        "blocks/ln1": (L, d), "blocks/wq": (L, d, H * hd),
-        "blocks/wk": (L, d, KV * hd), "blocks/wv": (L, d, KV * hd),
-        "blocks/wo": (L, H * hd, d), "blocks/ln2": (L, d),
-        "blocks/w_gate": (L, d, f), "blocks/w_up": (L, d, f),
-        "blocks/w_down": (L, f, d),
-    })
+    return refs.module(conf).shapes(conf)
+
+
+def rules(conf: dict) -> dict:
+    """{name: {"dtype", "init"}} of every leaf: the rule above, with the
+    reference module's `leaf_rules` over it."""
+    ref = refs.module(conf)
+    own = ref.leaf_rules(conf) if hasattr(ref, "leaf_rules") else {}
+    names = shapes(conf)
+    unknown = sorted(set(own) - set(names))
+    if unknown:
+        raise ValueError(f"{conf['reference']}.leaf_rules names no leaf of "
+                         f"its layout: {', '.join(unknown)}")
+    out = {}
+    for name in names:
+        rule = {"dtype": conf["dtypes"]["params"],
+                "init": ("zeros" if name.endswith(("norm", "ln1", "ln2"))
+                         else "normal")}
+        rule.update(own.get(name, {}))
+        if rule["init"] not in INITS:
+            raise ValueError(f"{name}: init {rule['init']!r} is none of "
+                             f"{INITS}")
+        out[name] = rule
     return out
 
 
@@ -45,12 +58,13 @@ def count(conf: dict) -> int:
 
 
 def _make(conf: dict, key) -> dict:
-    dtype = jnp.dtype(conf["dtypes"]["params"])
+    leaf_rules = rules(conf)
     items = sorted(shapes(conf).items())
     keys = jax.random.split(key, len(items))
     params: dict = {}
     for k, (name, shape) in zip(keys, items):
-        if name.endswith(("norm", "ln1", "ln2")):
+        dtype = jnp.dtype(leaf_rules[name]["dtype"])
+        if leaf_rules[name]["init"] == "zeros":
             leaf = jnp.zeros(shape, dtype)
         else:
             leaf = (STD * jax.random.normal(k, shape, jnp.float32)).astype(dtype)
